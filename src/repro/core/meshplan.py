@@ -145,10 +145,12 @@ def plan_job(cfg: ArchConfig, shape: ShapeSpec, n_chips: int = 256,
     # microbatch accumulation: bound the per-device remat carry
     # (L_units x tokens_micro x d_model x 2B, x3 for f32 recurrent states)
     accum = 1
+    ce_chunk = 0
     if shape.kind == "train":
         batch_ways = 32 if n_chips > 256 else 16
         if rules.batch == ("data", "model"):
             batch_ways = 256
+        batch_ways = min(batch_ways, n_chips)
         tokens_loc = shape.global_batch * shape.seq_len / batch_ways
         fam_mult = 3 if cfg.family in ("ssm", "hybrid") else 1
         carry = (cfg.stack_n_layers * tokens_loc * cfg.d_model * 2
@@ -160,6 +162,12 @@ def plan_job(cfg: ArchConfig, shape: ShapeSpec, n_chips: int = 256,
         if accum > 1:
             notes.append(f"remat carry {carry/2**30:.0f}GiB -> "
                          f"{accum}x grad accumulation")
+        # sequence-chunked CE: bound one chunk's f32 logits per device
+        rows = max(1.0, shape.global_batch / batch_ways / accum)
+        ce_chunk = 1024
+        while ce_chunk > 128 and \
+                rows * ce_chunk * cfg.padded_vocab * 4 > 2 ** 30:
+            ce_chunk //= 2
 
     if optimized and shape.kind == "train" and policy != "none":
         if cfg.moe is None and cfg.family in ("dense", "vlm", "audio") \
@@ -178,6 +186,5 @@ def plan_job(cfg: ArchConfig, shape: ShapeSpec, n_chips: int = 256,
 
     return JobPlan(arch=cfg.name, shape=shape.name, profile=profile,
                    rules=rules, moe_impl=moe_impl, optimizer=optimizer,
-                   remat=(shape.kind == "train"),
-                   ce_chunk=1024 if shape.kind == "train" else 0,
+                   remat=(shape.kind == "train"), ce_chunk=ce_chunk,
                    accum_steps=accum, notes="; ".join(notes))

@@ -10,5 +10,7 @@ scheduler manages, exercised by the roofline/perf iterations:
   wkv6              RWKV6 data-dependent-decay recurrence
 
 Each kernel has a pure-jnp oracle in ``ref.py`` and a jitted dispatcher in
-``ops.py``; tests sweep shapes/dtypes and assert allclose in interpret mode.
+``ops.py``.  The CPU tests sweep shapes/dtypes against the oracles with
+``interpret=True``; ``tests/test_chip_compile.py`` compiles each kernel for a
+TPU v5e at real widths, and ``chip_smoke.py`` runs them on the chip.
 """

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -123,7 +121,7 @@ def flash_attention_bkgs(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import SHAPES, get_config, list_configs, \
     shape_skip_reason
 from repro.core.meshplan import plan_job
@@ -72,15 +71,11 @@ def build_cell(cfg, shape, mesh, plan, ctx_overrides=None):
 
     if shape.kind == "train":
         opt = get_optimizer(plan.optimizer, warmup_cosine(3e-4, 100, 10000))
-        opt_struct = jax.eval_shape(opt.init, params_struct)
-        opt_axes = MX.opt_state_axes(plan.optimizer, params_struct, axes)
-        orules = rules if rules.opt_fsdp is None else \
-            dataclasses.replace(rules, fsdp=rules.opt_fsdp)
-        oshard = MX.tree_shardings(mesh, orules, opt_struct, opt_axes)
-        state_struct = {"params": params_struct, "opt_state": opt_struct,
+        state_struct = {"params": params_struct,
+                        "opt_state": jax.eval_shape(opt.init, params_struct),
                         "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        state_shard = {"params": pshard, "opt_state": oshard,
-                       "step": MX.scalar_sharding(mesh)}
+        state_shard = MX.train_state_shardings(mesh, rules, cfg,
+                                               plan.optimizer, state_struct)
         extras_keys = [k for k in ("media", "frames") if k in specs]
 
         A = accum_override if accum_override is not None \
@@ -180,7 +175,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         # alias inputs — the steady-state HBM picture, not double-buffered
         donate = (0,) if shape.kind == "train" else \
             ((2,) if shape.kind == "decode" else ())
-        with compat.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=shards,
                               donate_argnums=donate).lower(*args)
             t_low = time.time() - t0

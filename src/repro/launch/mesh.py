@@ -6,9 +6,8 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ArchConfig, ShapeSpec
 from repro.models import model as M
 from repro.models.sharding import Rules, spec as rules_spec
@@ -17,7 +16,18 @@ from repro.models.sharding import Rules, spec as rules_spec
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def make_host_mesh(devices):
+    """("data", "model") mesh over one host's chips.  A host is inside one
+    ICI domain, so tensor parallelism takes up to 16 of its chips (the
+    production mesh's ``model`` width) and data parallelism the rest."""
+    n = len(devices)
+    model = min(n, 16)
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
 
 
 def effective_rules(rules: Rules, mesh) -> Rules:
@@ -115,6 +125,27 @@ def opt_state_axes(optimizer_name: str, params_struct, param_axes):
     flat_a = treedef.flatten_up_to(param_axes)
     return treedef.unflatten([st_axes(s, a)
                               for s, a in zip(flat_s, flat_a)])
+
+
+def train_state_shardings(mesh, rules: Rules, cfg: ArchConfig,
+                          optimizer_name: str, state_struct):
+    """Shardings for a train-state tree (``TrainState.tree()`` layout):
+    params by their logical axes, optimizer state likewise (over
+    ``rules.opt_fsdp`` for ZeRO-1), the step counter replicated."""
+    rules = effective_rules(rules, mesh)
+    axes = M.param_axes(cfg)
+    params = state_struct["params"]
+    pshard = tree_shardings(mesh, rules, params, axes)
+    orules = rules if rules.opt_fsdp is None else \
+        dataclasses.replace(rules, fsdp=rules.opt_fsdp)
+    out = {"params": pshard,
+           "opt_state": tree_shardings(
+               mesh, orules, state_struct["opt_state"],
+               opt_state_axes(optimizer_name, params, axes)),
+           "step": scalar_sharding(mesh)}
+    if "err_state" in state_struct:
+        out["err_state"] = pshard
+    return out
 
 
 # --------------------------------------------------------------------------

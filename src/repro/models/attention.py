@@ -144,7 +144,7 @@ def _rect_attention(q, k, v, q_pos, kv_pos, *, causal, window, softcap,
 
 def full_attention(params, x, *, cfg, kind, rules, impl="xla_rect",
                    positions=None, kv=None, kv_pos=None, causal=True,
-                   softcap=None):
+                   softcap=None, interpret=False):
     """Self (or cross, via kv=) attention over a full sequence."""
     B, S, _ = x.shape
     if positions is None:
@@ -171,7 +171,7 @@ def full_attention(params, x, *, cfg, kind, rules, impl="xla_rect",
     elif impl == "pallas":
         from repro.kernels import ops as kops
         ctx = kops.flash_attention(q, k, v, causal=causal, window=window,
-                                   softcap=sc)
+                                   softcap=sc, interpret=interpret)
     else:
         ctx = _rect_attention(q, k, v, positions[0], kvp, causal=causal,
                               window=window, softcap=sc)

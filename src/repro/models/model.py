@@ -39,6 +39,7 @@ class Ctx:
     attn_impl: str = "xla_rect"      # xla_rect | xla_flash | pallas
     rnn_impl: str = "xla"            # xla | pallas
     moe_impl: str = "dense"          # dense | ep | ep_a2a
+    interpret: bool = False          # Pallas kernels through the interpreter
     remat: bool = True
     ce_chunk: int = 0                # sequence chunking for the CE logits
 
@@ -102,7 +103,8 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
         else:
             y, (kc, vc) = A.full_attention(
                 params["mixer"], h, cfg=cfg, kind=kind, rules=ctx.rules,
-                impl=ctx.attn_impl, positions=positions)
+                impl=ctx.attn_impl, positions=positions,
+                interpret=ctx.interpret)
             if mode == "prefill":
                 c0 = A.init_cache(cfg, kind, x.shape[0], cache_len, x.dtype)
                 pos2d = positions if positions is not None else \
@@ -112,12 +114,12 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
     elif kind == "rglru":
         y, st = RG.apply_block(params["mixer"], h, cfg=cfg, rules=ctx.rules,
                                state=cache if mode == "decode" else None,
-                               impl=ctx.rnn_impl)
+                               impl=ctx.rnn_impl, interpret=ctx.interpret)
         new_cache = st if mode != "train" else None
     else:  # rwkv
         y, st = RW.apply_timemix(params["mixer"], h, cfg=cfg, rules=ctx.rules,
                                  state=cache if mode == "decode" else None,
-                                 impl=ctx.rnn_impl)
+                                 impl=ctx.rnn_impl, interpret=ctx.interpret)
         new_cache = dict(st) if mode != "train" else None
     x = x + y
     h2 = L.apply_norm(params["norm2"], x, cfg.norm_type)
@@ -262,7 +264,7 @@ def encode(cfg, params, frames, ctx: Ctx):
         a = L.apply_norm(lp["norm1"], h, cfg.norm_type)
         y, _ = A.full_attention(lp["attn"], a, cfg=cfg, kind="attn",
                                 rules=ctx.rules, impl=ctx.attn_impl,
-                                causal=False)
+                                causal=False, interpret=ctx.interpret)
         h = h + y
         m = L.apply_norm(lp["norm2"], h, cfg.norm_type)
         h = h + L.apply_mlp(lp["mlp"], m, "gelu")
@@ -321,7 +323,8 @@ def _run_stack(cfg, params, x, ctx: Ctx, mode, caches=None, positions=None,
                     y, _ = A.full_attention(cross_p["attn"], hq, cfg=cfg,
                                             kind="attn", rules=ctx.rules,
                                             impl=ctx.attn_impl, kv=cross_kv,
-                                            causal=False)
+                                            causal=False,
+                                            interpret=ctx.interpret)
                 h = h + y
         return (h, aux_sum), (new_c if mode != "train" else 0)
 

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models import layers as L
 from repro.models.sharding import Rules
 
@@ -184,7 +183,7 @@ def _hier_gather(w, fsdp_axes, axis):
 def _ep_local(x_loc, router, wg, wu, wd, *, cfg, expert_axis, batch_axes,
               fsdp_axes=None):
     E, k = cfg.moe.n_experts, cfg.moe.top_k
-    n_shards = compat.axis_size(expert_axis)
+    n_shards = jax.lax.axis_size(expert_axis)
     n_local = E // n_shards
     me = jax.lax.axis_index(expert_axis)
     if fsdp_axes:
@@ -212,7 +211,7 @@ def _ep_a2a_local(x_loc, router, wg, wu, wd, *, cfg, expert_axis,
     DeepSeek-style EP used when activations are sharded too finely for the
     replicated-activation psum path."""
     E, k = cfg.moe.n_experts, cfg.moe.top_k
-    n_owner = compat.axis_size(expert_axis)
+    n_owner = jax.lax.axis_size(expert_axis)
     n_local = E // n_owner
     me = jax.lax.axis_index(expert_axis)
     Tl, D = x_loc.shape
@@ -288,7 +287,7 @@ def apply_ep(params, x, cfg, rules: Rules, mesh, impl="ep"):
                   else P(expert_axis, None, None))
         in_specs = (P(batch_ax, None), P(None, None), wspec, wspec, wdspec)
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs,
         out_specs=(P(batch_ax, None), P()), check_vma=False)(
         xt, params["router"], params["w_gate"], params["w_up"],

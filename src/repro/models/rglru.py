@@ -137,7 +137,8 @@ def block_params(key, cfg, dtype):
     return rglru_params(key, cfg, dtype)
 
 
-def apply_block(params, x, *, cfg, rules, state=None, impl="xla"):
+def apply_block(params, x, *, cfg, rules, state=None, impl="xla",
+                interpret=False):
     """Griffin recurrent temporal block.
 
     x: [B, S, D].  state: None (train) or dict(conv [B, cw-1, W], h [B, W]).
@@ -151,7 +152,7 @@ def apply_block(params, x, *, cfg, rules, state=None, impl="xla"):
         if impl == "pallas":
             from repro.kernels import ops as kops
             log_a, gated = _gates(params, u, params["w_rgate"].shape[0])
-            h, h_last = kops.rglru_scan(log_a, gated)
+            h, h_last = kops.rglru_scan(log_a, gated, interpret=interpret)
             h = h.astype(u.dtype)
             h_last = h_last.astype(jnp.float32)
         else:

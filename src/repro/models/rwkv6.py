@@ -141,7 +141,8 @@ def _heads(x, H):
     return x.reshape(B, S, H, D // H)
 
 
-def apply_timemix(params, x, *, cfg, rules, state=None, impl="xla"):
+def apply_timemix(params, x, *, cfg, rules, state=None, impl="xla",
+                  interpret=False):
     """x: [B, S, D] -> (y, new_state dict(x_tm [B,D], s [B,H,hd,hd]))."""
     B, S, D = x.shape
     H, hd = cfg.n_heads, D // cfg.n_heads
@@ -167,7 +168,8 @@ def apply_timemix(params, x, *, cfg, rules, state=None, impl="xla"):
         y = y[:, None]
     elif impl == "pallas":
         from repro.kernels import ops as kops
-        y, s_last = kops.wkv6(r, k, v, w, params["u"], s0)
+        y, s_last = kops.wkv6(r, k, v, w, params["u"], s0,
+                              interpret=interpret)
     else:
         y, s_last = wkv(r, k, v, w, params["u"], s0, rules=rules)
     # per-head layer norm, silu(g) gate, output proj

@@ -64,6 +64,12 @@ class Engine:
         self.cfg, self.params, self.ctx = cfg, params, ctx
         self.B, self.cache_len = batch_slots, cache_len
         self.state = M.init_decode_state(cfg, batch_slots, cache_len, dtype)
+        # each cache leaf's batch axis, from its logical axes: guessing it
+        # from sizes fails when a stacked cache's n_units equals B
+        self._batch_axis = jax.tree.map(
+            lambda ax: ax.index("batch"), M.decode_state_axes(cfg)["caches"],
+            is_leaf=lambda x: isinstance(x, tuple))
+        self.last_logits = None      # [B, Vp] of the latest decode step
         self.cur_tok = jnp.zeros((batch_slots,), jnp.int32)
         self.slot_req: List[Optional[Request]] = [None] * batch_slots
         self.slot_out: List[List[int]] = [[] for _ in range(batch_slots)]
@@ -79,14 +85,13 @@ class Engine:
     def submit(self, req: Request):
         self.queue.append(req)
 
+    def prefill(self, prompt):
+        """Prefill one prompt [S] -> (logits [1, Vp], its decode state)."""
+        return self._prefill(self.params, prompt[None, :])
+
     def _splice_slot(self, slot: int, logits, pstate):
         """Insert a prefilled request's cache into the batch cache."""
-        def put(batch_leaf, single_leaf):
-            # caches have batch as axis 0 (tail) or axis 1 (stacked units)
-            if batch_leaf.ndim == single_leaf.ndim:
-                ax = 1 if batch_leaf.shape[0] != self.B else 0
-            else:
-                ax = 0
+        def put(batch_leaf, single_leaf, ax):
             idx = [slice(None)] * batch_leaf.ndim
             idx[ax] = slice(slot, slot + 1)
             take = [slice(None)] * single_leaf.ndim
@@ -94,7 +99,7 @@ class Engine:
             return batch_leaf.at[tuple(idx)].set(single_leaf[tuple(take)])
 
         self.state["caches"] = jax.tree.map(
-            put, self.state["caches"], pstate["caches"])
+            put, self.state["caches"], pstate["caches"], self._batch_axis)
         self.state["pos"] = self.state["pos"].at[slot].set(pstate["pos"][0])
         tok = int(jnp.argmax(logits[0]))
         self.cur_tok = self.cur_tok.at[slot].set(tok)
@@ -113,8 +118,7 @@ class Engine:
             # within the same admit pass.
             while self.slot_req[slot] is None and self.queue:
                 req = self.queue.popleft()
-                logits, pstate = self._prefill(self.params,
-                                               req.prompt[None, :])
+                logits, pstate = self.prefill(req.prompt)
                 self._splice_slot(slot, logits, pstate)
                 self.slot_req[slot] = req
                 tok = int(self.cur_tok[slot])
@@ -133,6 +137,7 @@ class Engine:
             return 0
         logits, self.state = self._decode(self.params, self.cur_tok,
                                           self.state)
+        self.last_logits = logits
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self.cur_tok = next_tok
         for s in active:

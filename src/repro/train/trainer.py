@@ -97,15 +97,23 @@ def train_loop(cfg, state: TrainState, step_fn, data_iter, n_steps: int,
                ckpt_dir: Optional[str] = None, ckpt_every: int = 0,
                log_every: int = 10, extras: Optional[Dict] = None,
                log_fn=print):
-    """Simple host-side loop used by examples/ and launch/train.py."""
+    """Simple host-side loop used by examples/ and launch/train.py.
+
+    The state tree is donated to each step, so its buffers are reused for
+    the next state (the arrays in ``state`` are invalid afterwards; use the
+    returned tree).  Returns (tree, last metrics), with the per-step losses
+    as ``metrics["loss_history"]`` [n_steps].
+    """
     from repro.ckpt import checkpoint as CK
-    jitted = jax.jit(step_fn)
+    jitted = jax.jit(step_fn, donate_argnums=(0,))
     tree = state.tree()
     pending = None
+    losses = []
     t0 = time.time()
     for i in range(n_steps):
         tokens, labels = next(data_iter)
         tree, metrics = jitted(tree, tokens, labels, extras or {})
+        losses.append(metrics["loss"])
         if log_every and (i + 1) % log_every == 0:
             loss = float(metrics["loss"])
             rate = (i + 1) / (time.time() - t0)
@@ -117,4 +125,4 @@ def train_loop(cfg, state: TrainState, step_fn, data_iter, n_steps: int,
             pending = CK.save_async(ckpt_dir, tree, int(tree["step"]))
     if pending is not None:
         pending.join()
-    return tree, metrics
+    return tree, {**metrics, "loss_history": jnp.stack(losses)}

@@ -100,8 +100,9 @@ def test_pallas_rnn_impl_parity(arch):
     tokens, _, kwargs = _inputs(cfg, key)
     lx, _ = jax.jit(lambda p: M.forward(
         cfg, p, tokens, M.Ctx(rnn_impl="xla"), **kwargs))(params)
+    ctx = M.Ctx(rnn_impl="pallas", interpret=True)
     lp, _ = jax.jit(lambda p: M.forward(
-        cfg, p, tokens, M.Ctx(rnn_impl="pallas"), **kwargs))(params)
+        cfg, p, tokens, ctx, **kwargs))(params)
     assert jnp.max(jnp.abs(lx - lp)) < 5e-3, \
         float(jnp.max(jnp.abs(lx - lp)))
 

@@ -1,4 +1,8 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles.
+
+The kernels run through the Pallas interpreter, asked for explicitly with
+``interpret=True``; ``test_chip_compile.py`` compiles them for the chip.
+"""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,7 +29,7 @@ def test_flash_attention_sweep(B, S, T, H, K, hd, causal, window, dtype):
     k = jax.random.normal(ks[1], (B, T, K, hd)).astype(dtype)
     v = jax.random.normal(ks[2], (B, T, K, hd)).astype(dtype)
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=32, block_kv=32)
+                              block_q=32, block_kv=32, interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     assert jnp.max(jnp.abs(out.astype(jnp.float32)
@@ -38,7 +42,7 @@ def test_flash_attention_softcap():
     k = jax.random.normal(ks[1], (1, 64, 2, 32))
     v = jax.random.normal(ks[2], (1, 64, 2, 32))
     out = ops.flash_attention(q, k, v, causal=True, softcap=20.0,
-                              block_q=16, block_kv=16)
+                              block_q=16, block_kv=16, interpret=True)
     want = ref.attention_ref(q, k, v, causal=True, softcap=20.0)
     assert jnp.max(jnp.abs(out - want)) < 2e-5
 
@@ -54,7 +58,7 @@ def test_decode_attention_sweep(C, window, block):
     cpos = jnp.tile(jnp.arange(C)[None], (B, 1)).at[:, -5:].set(-1)
     cur = jnp.array([min(40, C - 1), C - 6, 10])
     out = ops.decode_attention(q, k, v, cpos, cur, window=window,
-                               block_kv=block)
+                               block_kv=block, interpret=True)
     want = ref.decode_attention_ref(q, k, v, cpos, cur, window=window)
     assert jnp.max(jnp.abs(out - want)) < 2e-5
 
@@ -71,7 +75,8 @@ def test_decode_attention_ring_wrap():
             + jnp.arange(C))[None]
     cpos = jnp.where(cpos > 68, cpos - C, cpos)
     cur = jnp.array([68])
-    out = ops.decode_attention(q, k, v, cpos, cur, window=16, block_kv=8)
+    out = ops.decode_attention(q, k, v, cpos, cur, window=16, block_kv=8,
+                               interpret=True)
     want = ref.decode_attention_ref(q, k, v, cpos, cur, window=16)
     assert jnp.max(jnp.abs(out - want)) < 2e-5
 
@@ -83,7 +88,8 @@ def test_rglru_property(B, S, W):
     k1, k2 = jax.random.split(jax.random.PRNGKey(B * S + W))
     la = -jnp.abs(jax.random.normal(k1, (B, S, W))) * 0.5 - 0.01
     x = jax.random.normal(k2, (B, S, W))
-    h, hl = ops.rglru_scan(la, x, block_t=16, block_w=16)
+    h, hl = ops.rglru_scan(la, x, block_t=16, block_w=16,
+                           interpret=True)
     h2, hl2 = ref.rglru_scan_ref(la, x)
     assert jnp.max(jnp.abs(h - h2)) < 1e-4
     assert jnp.max(jnp.abs(hl - hl2)) < 1e-4
@@ -94,7 +100,8 @@ def test_rglru_decay_bounds():
     B, S, W = 1, 64, 32
     la = jnp.full((B, S, W), -50.0)                 # a ~ 0
     x = jnp.ones((B, S, W))
-    h, _ = ops.rglru_scan(la, x, block_t=16, block_w=16)
+    h, _ = ops.rglru_scan(la, x, block_t=16, block_w=16,
+                          interpret=True)
     assert jnp.allclose(h, jnp.sqrt(-jnp.expm1(2 * la)) * x, atol=1e-5)
 
 
@@ -109,7 +116,7 @@ def test_wkv6_sweep(S, H, hd, bt):
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd))) * 0.5 + 0.4
     u = jax.random.normal(ks[4], (H, hd))
     s0 = jax.random.normal(jax.random.PRNGKey(9), (B, H, hd, hd)) * 0.1
-    y, s = ops.wkv6(r, k, v, w, u, s0, block_t=bt)
+    y, s = ops.wkv6(r, k, v, w, u, s0, block_t=bt, interpret=True)
     y2, s2 = ref.wkv6_ref(
         *(a.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
           for a in (r, k, v, w)),
@@ -130,11 +137,12 @@ def test_wkv6_state_carry_composes():
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd))) * 0.3 + 0.6
     u = jax.random.normal(ks[4], (H, hd))
     s0 = jnp.zeros((B, H, hd, hd))
-    y_full, s_full = ops.wkv6(r, k, v, w, u, s0, block_t=16)
+    y_full, s_full = ops.wkv6(r, k, v, w, u, s0, block_t=16,
+                             interpret=True)
     h = S // 2
     y1, s1 = ops.wkv6(r[:, :h], k[:, :h], v[:, :h], w[:, :h], u, s0,
-                      block_t=16)
+                      block_t=16, interpret=True)
     y2, s2 = ops.wkv6(r[:, h:], k[:, h:], v[:, h:], w[:, h:], u, s1,
-                      block_t=16)
+                      block_t=16, interpret=True)
     assert jnp.max(jnp.abs(jnp.concatenate([y1, y2], 1) - y_full)) < 1e-4
     assert jnp.max(jnp.abs(s2 - s_full)) < 1e-4

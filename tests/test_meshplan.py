@@ -1,5 +1,5 @@
 """Planner (meshplan) decisions: layouts, optimizers, accumulation."""
-from repro.configs import SHAPES, get_config
+from repro.configs import SHAPES, ShapeSpec, get_config
 from repro.core.meshplan import plan_job
 from repro.core.profiles import Profile
 
@@ -62,3 +62,14 @@ def test_policy_none_disables_optimization():
     opt = plan_job(get_config("qwen2-0.5b"), SHAPES["train_4k"],
                    optimized=True, policy="none")
     assert opt.rules.vocab == "model"          # stays at baseline layout
+
+
+def test_one_chip_train_plan_bounds_loss_chunk():
+    """On one chip the whole batch is local: the CE chunk shrinks until one
+    chunk's f32 logits fit 1 GiB (8 x 128 x 153600 x 4 B), and chunking
+    applies because the chunk is shorter than the sequence."""
+    cfg = get_config("qwen2-0.5b")
+    p = plan_job(cfg, ShapeSpec("cli", "train", 512, 8), n_chips=1)
+    assert p.remat and p.accum_steps == 1
+    assert p.ce_chunk == 128
+    assert 8 * p.ce_chunk * cfg.padded_vocab * 4 <= 2 ** 30

@@ -410,3 +410,31 @@ def test_admit_order_is_fifo(engine_setup):
                            max_new_tokens=2))
     fins = eng.run_to_completion()
     assert [f.uid for f in fins] == [0, 1, 2, 3]  # deque preserves order
+
+
+def _offline_greedy(cfg, params, ctx, prompt, n_tokens):
+    import jax.numpy as jnp
+    from repro.models import model as M
+    lg, st = M.prefill(cfg, params, prompt[None], 64, ctx)
+    toks = [int(jnp.argmax(lg[0]))]
+    for _ in range(n_tokens - 1):
+        lg, st = M.decode_step(cfg, params, jnp.array([toks[-1]], jnp.int32),
+                               st, ctx)
+        toks.append(int(jnp.argmax(lg[0])))
+    return toks
+
+
+def test_splice_finds_batch_axis_when_units_equal_slots(engine_setup):
+    """A stacked cache is [n_units, B, ...]; with n_units == B the request
+    must still land on the batch axis, not on the unit axis."""
+    import jax.numpy as jnp
+    from repro.serve.engine import Request
+    cfg, params, ctx = engine_setup
+    eng = make_engine(engine_setup, batch_slots=cfg.n_units)
+    prompts = [jnp.arange(5, dtype=jnp.int32),
+               jnp.arange(7, 16, dtype=jnp.int32)]
+    for uid, prompt in enumerate(prompts):
+        eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=4))
+    got = {f.uid: f.tokens for f in eng.run_to_completion()}
+    for uid, prompt in enumerate(prompts):
+        assert got[uid] == _offline_greedy(cfg, params, ctx, prompt, 4)

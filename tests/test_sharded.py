@@ -13,8 +13,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, tempfile, dataclasses
 sys.path.insert(0, %(src)r)
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_config, scaled_down
 import repro.configs.base as CB
 from repro.models import model as M
@@ -22,7 +21,8 @@ from repro.models.sharding import Rules
 from repro.launch import mesh as MX
 from repro.ckpt import checkpoint as CK
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 key = jax.random.PRNGKey(0)
 B, S = 8, 32
 
@@ -44,7 +44,7 @@ for arch, impls in [("llama3.2-1b", ["dense"]),
                                    jax.eval_shape(lambda: params),
                                    M.param_axes(cfg))
         tshard = NamedSharding(mesh, P(("pod", "data"), None))
-        with compat.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             loss, _ = jax.jit(
                 lambda p, t, y: M.lm_loss(cfg, p, t, y, ctx),
                 in_shardings=(pshard, tshard, tshard))(params, tokens,
@@ -60,7 +60,8 @@ params = M.init_params(cfg, key, jnp.float32, max_seq=64)
 axes = M.param_axes(cfg)
 with tempfile.TemporaryDirectory() as d:
     CK.save(d, params, step=1)
-    mesh_b = compat.make_mesh((4, 2), ("data", "model"))
+    mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
     shardings = MX.tree_shardings(mesh_b, Rules(),
                                   jax.eval_shape(lambda: params), axes)
     flat_names = []

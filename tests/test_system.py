@@ -113,3 +113,23 @@ def test_serving_matches_offline_decode():
         toks.append(int(jnp.argmax(lg[0])))
         cur = jnp.array([toks[-1]], jnp.int32)
     assert out == toks
+
+
+def test_donated_train_loop_matches_undonated_steps():
+    """train_loop donates the state to each step: same losses as the plain
+    jitted step, and the caller's input buffers are consumed."""
+    cfg, opt, state, ctx, step, data = _setup()
+    jitted = jax.jit(step)
+    tree = state.tree()
+    want = []
+    for tok, lab in (next(data) for _ in range(3)):
+        tree, mets = jitted(tree, tok, lab, {})
+        want.append(float(mets["loss"]))
+
+    fresh = init_state(cfg, jax.random.PRNGKey(0), opt, max_seq=64)
+    data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=32,
+                                  global_batch=4))
+    out, mets = train_loop(cfg, fresh, step, iter(data), 3, log_every=0)
+    assert mets["loss_history"].tolist() == want
+    assert int(out["step"]) == 3
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(fresh.params))
