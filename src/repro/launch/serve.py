@@ -5,7 +5,9 @@ weights made from ``--seed`` (nothing is downloaded).
         --requests 16 --slots 8 --cache-len 2048 --prompt-lens 128,256,512
 
 Parameters and the KV cache are bfloat16 at the config's full width;
-``--reduced`` swaps in the scaled-down config of the same family.
+``--reduced`` swaps in the scaled-down config of the same family.  After
+the run it prints the engine's own record (``serve/trace.py``): ticks, host
+syncs per tick, the queue wait's 95th percentile and each span's self time.
 """
 from __future__ import annotations
 
@@ -19,16 +21,17 @@ from repro.configs import get_config, scaled_down
 from repro.launch import compile_cache
 from repro.models import model as M
 from repro.serve.engine import Engine, Request
+from repro.serve.trace import Trace
 
 
-def build_engine(cfg, *, slots, cache_len, seed=0):
+def build_engine(cfg, *, slots, cache_len, seed=0, trace=None):
     """Random bf16 parameters from ``seed`` (made on the device) + an
-    engine with a bf16 cache."""
+    engine with a bf16 cache, recording into ``trace`` if one is given."""
     params = jax.jit(lambda k: M.init_params(cfg, k, jnp.bfloat16,
                                              max_seq=cache_len))(
         jax.random.PRNGKey(seed))
     return Engine(cfg, params, batch_slots=slots, cache_len=cache_len,
-                  dtype=jnp.bfloat16)
+                  dtype=jnp.bfloat16, trace=trace)
 
 
 def make_requests(cfg, n, prompt_lens, max_new, seed=0, uid0=0):
@@ -62,8 +65,9 @@ def main(argv=None):
     if args.reduced:
         cfg = scaled_down(cfg)
     lens = [int(x) for x in args.prompt_lens.split(",")]
+    trace = Trace()
     eng = build_engine(cfg, slots=args.slots, cache_len=args.cache_len,
-                       seed=args.seed)
+                       seed=args.seed, trace=trace)
     for req in make_requests(cfg, args.requests, lens, args.max_new,
                              args.seed):
         eng.submit(req)
@@ -74,6 +78,7 @@ def main(argv=None):
     print(f"served {len(fins)} requests, {toks} tokens in {dt:.2f}s "
           f"(compiles included; {args.slots} slots, "
           f"{jax.devices()[0].device_kind})")
+    print(trace.summary_line())
     for f in sorted(fins, key=lambda f: f.uid)[:4]:
         print(f"  req {f.uid}: {f.tokens}")
     return fins

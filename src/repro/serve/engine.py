@@ -10,10 +10,15 @@ scheduling).
 This is the substrate the decode_32k / long_500k dry-run cells lower
 (``serve_step`` = one engine tick), and what ``examples/serve_batch.py``
 drives end-to-end on CPU with a reduced config.
+
+An engine given a ``repro.serve.trace.Trace`` records its steps as spans
+and counts its work and its host syncs (``serve/trace.py`` lists each);
+without one it records nothing.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 from typing import Any, Deque, Dict, List, Optional
 
@@ -21,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import model as M
+from repro.serve.trace import Trace
 
 
 @dataclasses.dataclass
@@ -58,10 +64,15 @@ class EngineIncomplete(RuntimeError):
             f"({len(finished)} finished)")
 
 
+_OFF = contextlib.nullcontext()
+
+
 class Engine:
     def __init__(self, cfg, params, batch_slots: int, cache_len: int,
-                 ctx: M.Ctx = M.Ctx(), dtype=jnp.float32):
+                 ctx: M.Ctx = M.Ctx(), dtype=jnp.float32,
+                 trace: Optional[Trace] = None):
         self.cfg, self.params, self.ctx = cfg, params, ctx
+        self.trace = trace
         self.B, self.cache_len = batch_slots, cache_len
         self.state = M.init_decode_state(cfg, batch_slots, cache_len, dtype)
         # each cache leaf's batch axis, from its logical axes: guessing it
@@ -76,14 +87,30 @@ class Engine:
         self.slot_budget = [0] * batch_slots
         self.queue: Deque[Request] = collections.deque()
         self.finished: List[Finished] = []
-        self._decode = jax.jit(
-            lambda p, t, s: M.decode_step(cfg, p, t, s, ctx))
-        self._prefill = jax.jit(
-            lambda p, t: M.prefill(cfg, p, t, cache_len, ctx))
+
+        # named, so that a profiler trace names their programs
+        # jit_serve_decode and jit_serve_prefill
+        def serve_decode(p, t, s):
+            return M.decode_step(cfg, p, t, s, ctx)
+
+        def serve_prefill(p, t):
+            return M.prefill(cfg, p, t, cache_len, ctx)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
+
+    def _span(self, name: str, uid: Optional[int] = None):
+        return _OFF if self.trace is None else self.trace.span(name, uid)
+
+    def _count(self, name: str, n: int = 1):
+        if self.trace is not None:
+            self.trace.count(name, n)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
         self.queue.append(req)
+        if self.trace is not None:
+            self.trace.submitted(req.uid)
 
     def prefill(self, prompt):
         """Prefill one prompt [S] -> (logits [1, Vp], its decode state)."""
@@ -98,10 +125,15 @@ class Engine:
             take[ax] = slice(0, 1)
             return batch_leaf.at[tuple(idx)].set(single_leaf[tuple(take)])
 
-        self.state["caches"] = jax.tree.map(
-            put, self.state["caches"], pstate["caches"], self._batch_axis)
-        self.state["pos"] = self.state["pos"].at[slot].set(pstate["pos"][0])
-        tok = int(jnp.argmax(logits[0]))
+        with self._span("splice"):
+            self.state["caches"] = jax.tree.map(
+                put, self.state["caches"], pstate["caches"],
+                self._batch_axis)
+            self.state["pos"] = self.state["pos"].at[slot].set(
+                pstate["pos"][0])
+        with self._span("first_token"):
+            self._count("host_syncs")
+            tok = int(jnp.argmax(logits[0]))
         self.cur_tok = self.cur_tok.at[slot].set(tok)
 
     def _finish_slot(self, slot: int):
@@ -118,36 +150,55 @@ class Engine:
             # within the same admit pass.
             while self.slot_req[slot] is None and self.queue:
                 req = self.queue.popleft()
-                logits, pstate = self.prefill(req.prompt)
-                self._splice_slot(slot, logits, pstate)
-                self.slot_req[slot] = req
-                tok = int(self.cur_tok[slot])
-                self.slot_out[slot] = [tok]
-                # the prefill-sampled token is the first emitted token, so
-                # only max_new_tokens - 1 decode steps remain.
-                self.slot_budget[slot] = req.max_new_tokens - 1
-                if self.slot_budget[slot] <= 0 or tok == req.eos_id:
-                    self._finish_slot(slot)
+                if self.trace is not None:
+                    self.trace.admitting(req.uid)
+                with self._span("admit", req.uid):
+                    self._count("admitted")
+                    self._count("prefill_tokens", len(req.prompt))
+                    with self._span("prefill"):
+                        logits, pstate = self.prefill(req.prompt)
+                    self._splice_slot(slot, logits, pstate)
+                    self.slot_req[slot] = req
+                    with self._span("first_token"):
+                        self._count("host_syncs")
+                        tok = int(self.cur_tok[slot])
+                    self.slot_out[slot] = [tok]
+                    # the prefill-sampled token is the first emitted
+                    # token, so only max_new_tokens - 1 decode steps remain.
+                    self.slot_budget[slot] = req.max_new_tokens - 1
+                    if self.slot_budget[slot] <= 0 or tok == req.eos_id:
+                        self._finish_slot(slot)
 
     def tick(self) -> int:
         """One engine iteration: admit, decode one token for all slots."""
-        self._admit()
-        active = [s for s in range(self.B) if self.slot_req[s] is not None]
-        if not active:
-            return 0
-        logits, self.state = self._decode(self.params, self.cur_tok,
-                                          self.state)
-        self.last_logits = logits
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self.cur_tok = next_tok
-        for s in active:
-            tok = int(next_tok[s])
-            self.slot_out[s].append(tok)
-            self.slot_budget[s] -= 1
-            req = self.slot_req[s]
-            if self.slot_budget[s] <= 0 or tok == req.eos_id:
-                self._finish_slot(s)
-        return len(active)
+        with self._span("tick"):
+            self._count("ticks")
+            self._admit()
+            active = [s for s in range(self.B)
+                      if self.slot_req[s] is not None]
+            if not active:
+                return 0
+            with self._span("decode"):
+                logits, self.state = self._decode(self.params, self.cur_tok,
+                                                  self.state)
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            self.last_logits = logits
+            self.cur_tok = next_tok
+            # the first int() below would wait here too; waiting first
+            # keeps the wait for the device apart from the pulls
+            with self._span("device_wait"):
+                jax.block_until_ready(next_tok)
+            with self._span("pull"):
+                self._count("host_syncs", len(active))
+                self._count("decoded_tokens", len(active))
+                for s in active:
+                    tok = int(next_tok[s])
+                    self.slot_out[s].append(tok)
+                    self.slot_budget[s] -= 1
+                    req = self.slot_req[s]
+                    if self.slot_budget[s] <= 0 or tok == req.eos_id:
+                        self._finish_slot(s)
+            return len(active)
 
     def run_to_completion(self, max_ticks: int = 10_000) -> List[Finished]:
         ticks = 0
