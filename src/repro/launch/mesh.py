@@ -213,5 +213,9 @@ def input_shardings(cfg: ArchConfig, shape: ShapeSpec, mesh,
                                       jnp.bfloat16)))
         brules = rules if batch_ax is not None else \
             dataclasses.replace(rules, batch=None)
+        # a cache row holds a position's K heads side by side: it is split
+        # in whole heads or not at all
+        if cfg.n_kv_heads % _axis_size(mesh, brules.kv_heads):
+            brules = dataclasses.replace(brules, kv_heads=None)
         out["state"] = tree_shardings(mesh, brules, state_struct, axes)
     return out

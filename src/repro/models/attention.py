@@ -183,43 +183,50 @@ def full_attention(params, x, *, cfg, kind, rules, impl="xla_rect",
 # KV cache (decode)
 # --------------------------------------------------------------------------
 def init_cache(cfg, kind, batch, max_len, dtype):
+    """One layer's KV cache: ``k``/``v`` [B, C, K*hd] (each position's
+    heads side by side in one row) and the position each slot holds."""
     C = max_len if (kind != "local" or not cfg.local_window) \
         else min(max_len, cfg.local_window)
-    K, hd = cfg.n_kv_heads, cfg.head_dim
+    kv = cfg.n_kv_heads * cfg.head_dim
     return {
-        "k": jnp.zeros((batch, C, K, hd), dtype),
-        "v": jnp.zeros((batch, C, K, hd), dtype),
+        "k": jnp.zeros((batch, C, kv), dtype),
+        "v": jnp.zeros((batch, C, kv), dtype),
         "pos": jnp.full((batch, C), -1, jnp.int32),
     }
 
 
 def cache_axes():
-    return {"k": ("batch", "cache_seq", "kv_heads", "head_dim"),
-            "v": ("batch", "cache_seq", "kv_heads", "head_dim"),
+    return {"k": ("batch", "cache_seq", "kv_heads"),
+            "v": ("batch", "cache_seq", "kv_heads"),
             "pos": ("batch", "cache_seq")}
 
 
-def _ring_write(cache, k_new, v_new, positions):
-    """Write one token per batch row at slot = pos % C."""
-    C = cache["k"].shape[1]
-    slots = positions % C
+def _ring_write(cache, k_new, v_new, positions, layer=None):
+    """Write each row's new token at slot ``pos % C``, in place.
 
-    def upd(buf, new, slot):
-        return jax.lax.dynamic_update_slice_in_dim(buf, new[None], slot,
-                                                   axis=0)
+    ``cache`` is one layer's ([B, C, ...]) or, with ``layer``, a stack of
+    layers ([n_layers, B, C, ...]) of which row ``b`` of layer ``layer`` is
+    written.  Only the B new rows are written; a donated cache is updated
+    where it lies.
+    """
+    B, C = positions.shape[0], cache["pos"].shape[-1]
+    at = (jnp.arange(B), positions % C)
+    if layer is not None:
+        at = (layer,) + at
 
-    k = jax.vmap(upd)(cache["k"], k_new, slots)
-    v = jax.vmap(upd)(cache["v"], v_new, slots)
-    pos = jax.vmap(
-        lambda p, s, val: jax.lax.dynamic_update_slice_in_dim(
-            p, val[None], s, axis=0))(cache["pos"], slots, positions)
-    return {"k": k, "v": v, "pos": pos}
+    def put(buf, new):
+        return buf.at[at].set(new.astype(buf.dtype), unique_indices=True)
+
+    return {"k": put(cache["k"], k_new), "v": put(cache["v"], v_new),
+            "pos": put(cache["pos"], positions)}
 
 
 def fill_cache(cache, k, v, positions):
-    """Prefill: write the (last C) tokens of k/v into the cache."""
+    """Prefill: write the (last C) tokens of k/v [B, S, K, hd] into the
+    cache."""
+    B, S = k.shape[:2]
+    k, v = k.reshape(B, S, -1), v.reshape(B, S, -1)
     C = cache["k"].shape[1]
-    S = k.shape[1]
     if S >= C:
         # keep the trailing C tokens; ring slot = pos % C keeps mask logic
         ktail, vtail = k[:, S - C:], v[:, S - C:]
@@ -237,33 +244,62 @@ def fill_cache(cache, k, v, positions):
     return {"k": k0, "v": v0, "pos": p0}
 
 
+def _attend_rows(q, k, v, valid, softcap):
+    """One query per row against keys and values stored as [B, C, K*hd].
+
+    q: [B, H, hd] -> [B, H, hd].  Each query head is put in its KV head's
+    columns of a block-diagonal [B, H, K*hd] matrix, so that one product
+    per row reads the stored rows whole, with no per-head transposed copy.
+    The zeros add nothing: the scores are the per-head products, summed in
+    float32, and the softmax is float32.  They cost K times the useful
+    FLOPs, H per cache byte read, which stays below a TPU's ridge for the
+    head counts here; a per-head view of the rows would copy the cache into
+    another layout instead.
+    """
+    B, H, hd = q.shape
+    K = k.shape[-1] // hd
+    own = (jnp.arange(H)[:, None] // (H // K) == jnp.arange(K)[None, :])
+    own = own[None, :, :, None]                              # [1, H, K, 1]
+    # q at its own precision times the scale (a power of two for the usual
+    # head sizes, so exact)
+    qs = (q * hd ** -0.5)[:, :, None, :]
+    qb = jnp.where(own, qs, 0).reshape(B, H, K * hd)
+    s = jnp.einsum("bnd,bcd->bnc", qb, k, preferred_element_type=jnp.float32)
+    s = L.softcap(s, softcap)
+    s = jnp.where(valid[:, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bnc,bcd->bnd", p, v, preferred_element_type=jnp.float32)
+    o = o.reshape(B, H, K, hd)
+    return jnp.where(own, o, 0.0).sum(axis=2)
+
+
 def decode_attention(params, x, cache, positions, *, cfg, kind, rules,
-                     impl="xla", cross_kv=None, cross_pos=None):
-    """One-token decode. x: [B, 1, D]; positions: [B] absolute positions."""
+                     layer=None, cross_kv=None):
+    """One-token decode. x: [B, 1, D]; positions: [B] absolute positions.
+
+    Self-attention writes the token's K/V into ``cache`` (a stacked cache
+    when ``layer`` is given, see ``_ring_write``) and attends over that
+    layer as stored (``_attend_rows``).  Cross-attention (``cross_kv``,
+    [B, T, K, hd] encoder memory) writes nothing.
+    """
     B = x.shape[0]
     is_cross = cross_kv is not None
     q = _project_q(params, x, cfg, positions[:, None], kind,
                    with_rope=not is_cross)
     if is_cross:
-        k, v = cross_kv                       # [B, T, K, hd] encoder memory
+        k, v = (a.reshape(a.shape[0], a.shape[1], -1) for a in cross_kv)
         valid = jnp.ones((B, k.shape[1]), bool)
         new_cache = cache
     else:
         k_new, v_new = _project_kv(params, x, cfg, positions[:, None], kind)
-        new_cache = _ring_write(cache, k_new[:, 0], v_new[:, 0], positions)
-        k, v = new_cache["k"], new_cache["v"]
-        cpos = new_cache["pos"]               # [B, C]
+        new_cache = _ring_write(cache, k_new.reshape(B, -1),
+                                v_new.reshape(B, -1), positions, layer)
+        mine = new_cache if layer is None else \
+            jax.tree.map(lambda a: a[layer], new_cache)
+        k, v, cpos = mine["k"], mine["v"], mine["pos"]      # cpos: [B, C]
         valid = (cpos >= 0) & (cpos <= positions[:, None])
         if kind == "local" and cfg.local_window:
             valid &= (positions[:, None] - cpos) < cfg.local_window
-    K, hd = k.shape[2], k.shape[3]
-    G = cfg.n_heads // K
-    qf = q[:, 0].reshape(B, K, G, hd).astype(jnp.float32) * hd ** -0.5
-    s = jnp.einsum("bkgh,bckh->bkgc", qf, k.astype(jnp.float32))
-    s = L.softcap(s, cfg.attn_softcap)
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bkgc,bckh->bkgh", p, v.astype(jnp.float32))
-    ctx = ctx.reshape(B, 1, cfg.n_heads, hd).astype(x.dtype)
-    y = _out_proj(params, ctx, rules)
+    ctx = _attend_rows(q[:, 0], k, v, valid, cfg.attn_softcap)
+    y = _out_proj(params, ctx[:, None].astype(x.dtype), rules)
     return y, new_cache

@@ -4,7 +4,8 @@ Layer stacks follow the config's ``pattern_unit × n_units + tail``
 factorization: parameters of repeated units are stacked on a leading axis and
 traversed with ``jax.lax.scan`` (optionally rematerialized), keeping HLO size
 bounded for 61-layer configs.  Caches/recurrent states are stacked the same
-way and threaded through the scan as xs/ys.
+way: prefill builds them as the scan's ys, decode carries them through the
+scan and updates each layer in place.
 
 Entry points
 ------------
@@ -90,16 +91,26 @@ def zero_aux():
 
 
 def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
-                 positions=None, cache_len=0):
-    """Pre-norm residual block.  Returns (x, new_cache, aux)."""
+                 positions=None, cache_len=0, layer=None):
+    """Pre-norm residual block.  Returns (x, new_cache, aux).
+
+    In decode mode ``cache`` is this block's cache, or with ``layer`` the
+    stack of its caches over the units, of which the block reads and
+    writes layer ``layer`` alone: an attention block the B new rows, a
+    recurrent block its whole (small) state.
+    """
     aux = zero_aux()
     h = L.apply_norm(params["norm1"], x, cfg.norm_type)
     new_cache = None
+    state = None
+    if mode == "decode" and kind not in ("attn", "local"):
+        state = cache if layer is None else \
+            jax.tree.map(lambda a: a[layer], cache)
     if kind in ("attn", "local"):
         if mode == "decode":
             y, new_cache = A.decode_attention(
                 params["mixer"], h, cache, positions, cfg=cfg, kind=kind,
-                rules=ctx.rules)
+                rules=ctx.rules, layer=layer)
         else:
             y, (kc, vc) = A.full_attention(
                 params["mixer"], h, cfg=cfg, kind=kind, rules=ctx.rules,
@@ -113,20 +124,19 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
                 new_cache = A.fill_cache(c0, kc, vc, pos2d)
     elif kind == "rglru":
         y, st = RG.apply_block(params["mixer"], h, cfg=cfg, rules=ctx.rules,
-                               state=cache if mode == "decode" else None,
-                               impl=ctx.rnn_impl, interpret=ctx.interpret)
+                               state=state, impl=ctx.rnn_impl,
+                               interpret=ctx.interpret)
         new_cache = st if mode != "train" else None
     else:  # rwkv
         y, st = RW.apply_timemix(params["mixer"], h, cfg=cfg, rules=ctx.rules,
-                                 state=cache if mode == "decode" else None,
-                                 impl=ctx.rnn_impl, interpret=ctx.interpret)
+                                 state=state, impl=ctx.rnn_impl,
+                                 interpret=ctx.interpret)
         new_cache = dict(st) if mode != "train" else None
     x = x + y
     h2 = L.apply_norm(params["norm2"], x, cfg.norm_type)
     if kind == "rwkv":
         f, x_cm = RW.apply_channelmix(
-            params["ffn"], h2, cfg=cfg, rules=ctx.rules,
-            state=cache if mode == "decode" else None)
+            params["ffn"], h2, cfg=cfg, rules=ctx.rules, state=state)
         if new_cache is not None:
             new_cache["x_cm"] = x_cm
     elif cfg.moe is not None and kind in ("attn", "local"):
@@ -136,6 +146,9 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
         f = L.apply_mlp(params["ffn"], h2, cfg.ffn_kind)
     x = x + f
     x = constrain(x, ctx.rules, ("batch", "seq", "embed"))
+    if state is not None and layer is not None:
+        new_cache = jax.tree.map(lambda a, n: a.at[layer].set(n), cache,
+                                 new_cache)
     return x, new_cache, aux
 
 
@@ -292,29 +305,38 @@ def _embed_tokens(cfg, params, tokens, media=None):
 
 def _run_stack(cfg, params, x, ctx: Ctx, mode, caches=None, positions=None,
                cache_len=0, enc_kv=None):
-    """Scan units + unrolled tail.  Returns (x, new_caches, aux_sum)."""
-    n_pat = len(cfg.pattern_unit)
+    """Scan units + unrolled tail.  Returns (x, new_caches, aux_sum).
+
+    Prefill's caches come out of the scan as its ys.  Decode carries the
+    stacked caches through the scan, so that each unit writes its layer of
+    them in place (a donated state is updated where it lies) instead of
+    the scan building a new stack every step.
+    """
     has_cross = cfg.encoder is not None
+    decoding = mode == "decode"
 
     def unit_body(carry, xs):
-        h, aux_sum = carry
+        h, aux_sum, stack = carry
         unit_p = xs["params"]
-        unit_c = xs.get("cache")
         cross_p = xs.get("cross")
         cross_kv = xs.get("enc_kv")
         new_c = {}
+        stack = dict(stack) if decoding else stack
         for i, kind in enumerate(cfg.pattern_unit):
-            c_in = None if unit_c is None else unit_c[f"b{i}"]
-            h, c_out, aux = _apply_block(cfg, kind, unit_p[f"b{i}"], h, ctx,
-                                         mode, cache=c_in,
-                                         positions=positions,
-                                         cache_len=cache_len)
-            if mode != "train":
-                new_c[f"b{i}"] = c_out
+            name = f"b{i}"
+            h, c_out, aux = _apply_block(
+                cfg, kind, unit_p[name], h, ctx, mode,
+                cache=stack[name] if decoding else None,
+                positions=positions, cache_len=cache_len,
+                layer=xs["layer"] if decoding else None)
+            if decoding:
+                stack[name] = c_out
+            elif mode == "prefill":
+                new_c[name] = c_out
             aux_sum = {k: aux_sum[k] + aux[k] for k in aux_sum}
             if has_cross and cross_p is not None:
                 hq = L.apply_norm(cross_p["norm"], h, cfg.norm_type)
-                if mode == "decode":
+                if decoding:
                     y, _ = A.decode_attention(cross_p["attn"], hq, None,
                                               positions, cfg=cfg, kind="attn",
                                               rules=ctx.rules,
@@ -326,18 +348,22 @@ def _run_stack(cfg, params, x, ctx: Ctx, mode, caches=None, positions=None,
                                             causal=False,
                                             interpret=ctx.interpret)
                 h = h + y
-        return (h, aux_sum), (new_c if mode != "train" else 0)
+        return (h, aux_sum, stack), (new_c if mode == "prefill" else 0)
 
     body = jax.checkpoint(unit_body) if (ctx.remat and mode == "train") \
         else unit_body
     xs = {"params": params["units"]}
-    if caches is not None:
-        xs["cache"] = caches["units"]
+    stack = None
+    if decoding:
+        stack = caches["units"]
+        xs["layer"] = jnp.arange(cfg.n_units)
     if has_cross:
         xs["cross"] = params["cross"]
         xs["enc_kv"] = enc_kv
-    (x, aux_sum), unit_caches = jax.lax.scan(
-        body, (x, zero_aux()), xs)
+    (x, aux_sum, stack), unit_caches = jax.lax.scan(
+        body, (x, zero_aux(), stack), xs)
+    if decoding:
+        unit_caches = stack
 
     tail_caches = []
     for i, kind in enumerate(cfg.tail):

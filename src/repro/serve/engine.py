@@ -96,7 +96,9 @@ class Engine:
         def serve_prefill(p, t):
             return M.prefill(cfg, p, t, cache_len, ctx)
 
-        self._decode = jax.jit(serve_decode)
+        # the decode state is donated: the step writes its new rows into
+        # the cache where it lies, and the engine keeps only the output
+        self._decode = jax.jit(serve_decode, donate_argnums=2)
         self._prefill = jax.jit(serve_prefill)
 
     def _span(self, name: str, uid: Optional[int] = None):

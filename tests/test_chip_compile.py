@@ -1,5 +1,6 @@
-"""Compile-for-chip checks: the Pallas kernels and one full-width prefill are
-compiled for a described TPU v5e (no chip attached) at real model widths.
+"""Compile-for-chip checks: the Pallas kernels, one full-width prefill and
+the serving decode step are compiled for a described TPU v5e (no chip
+attached) at real model widths.
 
 Every kernel must reach Mosaic (``tpu_custom_call`` in the compiled text): a
 kernel that the chip's compiler refuses, or that silently went through the
@@ -101,3 +102,32 @@ def test_qwen2_full_width_prefill_with_pallas_attention(one_chip):
     text = _compiled_text(
         lambda p, t: M.prefill(cfg, p, t, cache_len, ctx), params, tokens)
     assert "tpu_custom_call" in text
+
+
+def test_qwen2_decode_step_updates_cache_in_place(one_chip):
+    """The decode step at the chat cell's shape (128 slots x 1536), state
+    donated as the engine donates it: the whole stacked cache aliases the
+    outputs, the temporaries are a sliver of it (no relaid-out layer copy,
+    no new stack), and the only loop is the layer scan (no ring write
+    lowered to a loop of one-row updates)."""
+    cfg = get_config("qwen2-0.5b")
+    B, C = 128, 1536
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                            tree)
+
+    params = on_chip(jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16, max_seq=C)))
+    state = on_chip(jax.eval_shape(lambda: M.init_decode_state(
+        cfg, B, C, jnp.bfloat16)))
+    tokens = _spec((B,), jnp.int32, one_chip)
+    step = jax.jit(lambda p, t, s: M.decode_step(cfg, p, t, s),
+                   donate_argnums=2)
+    compiled = step.lower(params, tokens, state).compile()
+    ma = compiled.memory_analysis()
+    cache = sum(a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(state["caches"]))
+    assert ma.alias_size_in_bytes >= cache
+    assert ma.temp_size_in_bytes < cache // 100, ma.temp_size_in_bytes
+    assert compiled.as_text().count(" while(") == 1

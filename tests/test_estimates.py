@@ -210,9 +210,10 @@ def test_reserve_overlay_matches_legacy_masking(seed):
         assert (p1 is None) == (p2 is None)
         if p1 is not None:
             assert [w.node for w in p1] == [w.node for w in p2]
-        if rng.random() < 0.3 and b_ovl.workers:
-            name = rng.choice(sorted({w.job for ws in b_ovl.workers.values()
-                                      for w in ws}))
+        # release a placed gang; the index may hold only emptied nodes
+        placed = sorted({w.job for ws in b_ovl.workers.values() for w in ws})
+        if rng.random() < 0.3 and placed:
+            name = rng.choice(placed)
             for c, b in ((c_ovl, b_ovl), (c_msk, b_msk)):
                 victims = [w for ws in b.workers.values()
                            for w in ws if w.job == name]
