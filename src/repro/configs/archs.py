@@ -70,9 +70,13 @@ RWKV6_3B = register(ArchConfig(
     n_layers=32, d_model=2560, n_heads=40, n_kv_heads=40, head_dim=64,
     d_ff=8960, vocab=65_536,
     pattern_unit=("rwkv",), n_units=32,
-    norm_type="layer", tied_embeddings=False,
+    norm_type="layer", norm_eps=1e-5, embed_norm=True,
+    tied_embeddings=False,
+    mix_lora_rank=32, decay_lora_rank=64, head_size_divisor=8,
     subquadratic=True,                       # attention-free, O(1) state
-    source="arXiv:2404.05892; hf",
+    source="arXiv:2404.05892 (Eagle and Finch), Section 4; "
+           "hf:RWKV/v6-Finch-3B-HF config.json; LoRA ranks from RWKV-LM "
+           "RWKV_Tmix_x060",
 ))
 
 KIMI_K2 = register(ArchConfig(

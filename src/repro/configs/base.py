@@ -72,6 +72,8 @@ class ArchConfig:
     # ffn / norm
     ffn_kind: str = "swiglu"         # swiglu | geglu | gelu (2-matmul MLP)
     norm_type: str = "rms"           # rms | layer
+    norm_eps: float = 1e-6
+    embed_norm: bool = False         # a norm on the embeddings (RWKV's ln0)
     tied_embeddings: bool = True
     embed_scale: bool = False        # gemma-style sqrt(d_model) input scaling
 
@@ -81,6 +83,12 @@ class ArchConfig:
     # RG-LRU (hybrid family)
     rnn_width: int = 0
     conv_width: int = 4
+
+    # RWKV6 time-mix: LoRA ranks of the token-shift mix (ddlerp) and of the
+    # decay; the per-head output norm's eps is norm_eps * divisor ** 2
+    mix_lora_rank: int = 32
+    decay_lora_rank: int = 64
+    head_size_divisor: int = 8
 
     # enc-dec (audio family)
     encoder: Optional[EncoderSpec] = None
@@ -169,9 +177,12 @@ class ArchConfig:
             ffn = 3 * D * F if self.ffn_kind in ("swiglu", "geglu") else 2 * D * F
             return rec + ffn + norms
         if kind == "rwkv":
-            # time-mix: r,k,v,g,o projections + lora mixers; channel-mix: 2 mats
-            tm = 5 * D * D + 6 * 32 * 2 * D + 64 * D * 2 + 2 * D
-            cm = 2 * D * self.d_ff
+            # time-mix: r,k,v,g,o projections, the ddlerp and decay LoRAs,
+            # six shift mixes, decay base, bonus, output norm; channel-mix:
+            # key, value, receptance and two shift mixes
+            tm = (5 * D * D + 2 * 5 * self.mix_lora_rank * D
+                  + 2 * self.decay_lora_rank * D + 10 * D)
+            cm = 2 * D * self.d_ff + D * D + 2 * D
             return tm + cm + norms
         raise ValueError(kind)
 

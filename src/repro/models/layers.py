@@ -44,16 +44,16 @@ def norm_axes(kind):
     return {"scale": ("embed",), "bias": ("embed",)}
 
 
-def apply_norm(params, x, kind):
+def apply_norm(params, x, kind, eps):
     xf = x.astype(jnp.float32)
     if kind == "rms":
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                               + NORM_EPS)
+                               + eps)
         y = y * params["scale"].astype(jnp.float32)
     else:
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + NORM_EPS)
+        y = (xf - mu) * jax.lax.rsqrt(var + eps)
         y = y * params["scale"].astype(jnp.float32) \
             + params["bias"].astype(jnp.float32)
     return y.astype(x.dtype)

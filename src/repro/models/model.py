@@ -85,6 +85,10 @@ def _block_axes(cfg, kind):
     return ax
 
 
+def _norm(cfg, params, x):
+    return L.apply_norm(params, x, cfg.norm_type, cfg.norm_eps)
+
+
 def zero_aux():
     return {"load_balance": jnp.zeros((), jnp.float32),
             "router_z": jnp.zeros((), jnp.float32)}
@@ -100,7 +104,7 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
     recurrent block its whole (small) state.
     """
     aux = zero_aux()
-    h = L.apply_norm(params["norm1"], x, cfg.norm_type)
+    h = _norm(cfg, params["norm1"], x)
     new_cache = None
     state = None
     if mode == "decode" and kind not in ("attn", "local"):
@@ -130,10 +134,11 @@ def _apply_block(cfg, kind, params, x, ctx: Ctx, mode, cache=None,
     else:  # rwkv
         y, st = RW.apply_timemix(params["mixer"], h, cfg=cfg, rules=ctx.rules,
                                  state=state, impl=ctx.rnn_impl,
-                                 interpret=ctx.interpret)
+                                 interpret=ctx.interpret,
+                                 remat=ctx.remat and mode == "train")
         new_cache = dict(st) if mode != "train" else None
     x = x + y
-    h2 = L.apply_norm(params["norm2"], x, cfg.norm_type)
+    h2 = _norm(cfg, params["norm2"], x)
     if kind == "rwkv":
         f, x_cm = RW.apply_channelmix(
             params["ffn"], h2, cfg=cfg, rules=ctx.rules, state=state)
@@ -180,6 +185,9 @@ def init_params(cfg, key, dtype=jnp.float32, max_seq=4096):
     }
     if not cfg.tied_embeddings:
         params["unembed"] = L.embed_init(keys[1], (Vp, cfg.d_model), dtype)
+    if cfg.embed_norm:
+        params["embed_norm"] = L.norm_params(cfg.d_model, cfg.norm_type,
+                                             dtype)
     if not cfg.use_rope:
         params["pos_embed"] = L.embed_init(keys[2], (max_seq, cfg.d_model),
                                            dtype)
@@ -214,6 +222,8 @@ def param_axes(cfg):
     }
     if not cfg.tied_embeddings:
         axes["unembed"] = ("vocab", "embed")
+    if cfg.embed_norm:
+        axes["embed_norm"] = L.norm_axes(cfg.norm_type)
     if not cfg.use_rope:
         axes["pos_embed"] = (None, "embed")
 
@@ -274,19 +284,19 @@ def encode(cfg, params, frames, ctx: Ctx):
     x = frames + _sinusoids(e.n_ctx, e.d_model).astype(frames.dtype)
 
     def body(h, lp):
-        a = L.apply_norm(lp["norm1"], h, cfg.norm_type)
+        a = _norm(cfg, lp["norm1"], h)
         y, _ = A.full_attention(lp["attn"], a, cfg=cfg, kind="attn",
                                 rules=ctx.rules, impl=ctx.attn_impl,
                                 causal=False, interpret=ctx.interpret)
         h = h + y
-        m = L.apply_norm(lp["norm2"], h, cfg.norm_type)
+        m = _norm(cfg, lp["norm2"], h)
         h = h + L.apply_mlp(lp["mlp"], m, "gelu")
         return h, None
 
     if ctx.remat:
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["encoder"]["layers"])
-    return L.apply_norm(params["encoder"]["final_norm"], x, cfg.norm_type)
+    return _norm(cfg, params["encoder"]["final_norm"], x)
 
 
 # --------------------------------------------------------------------------
@@ -300,7 +310,11 @@ def _embed_tokens(cfg, params, tokens, media=None):
         x = jax.lax.dynamic_update_slice(x, media.astype(x.dtype), (0, 0, 0))
     if not cfg.use_rope:
         x = x + params["pos_embed"][None, :x.shape[1], :].astype(x.dtype)
-    return x
+    return _embed_norm(cfg, params, x)
+
+
+def _embed_norm(cfg, params, x):
+    return _norm(cfg, params["embed_norm"], x) if cfg.embed_norm else x
 
 
 def _run_stack(cfg, params, x, ctx: Ctx, mode, caches=None, positions=None,
@@ -335,7 +349,7 @@ def _run_stack(cfg, params, x, ctx: Ctx, mode, caches=None, positions=None,
                 new_c[name] = c_out
             aux_sum = {k: aux_sum[k] + aux[k] for k in aux_sum}
             if has_cross and cross_p is not None:
-                hq = L.apply_norm(cross_p["norm"], h, cfg.norm_type)
+                hq = _norm(cfg, cross_p["norm"], h)
                 if decoding:
                     y, _ = A.decode_attention(cross_p["attn"], hq, None,
                                               positions, cfg=cfg, kind="attn",
@@ -399,7 +413,7 @@ def forward(cfg, params, tokens, ctx: Ctx = Ctx(), media=None, frames=None):
             params["cross"])
     x, _, aux = _run_stack(cfg, params, x, ctx, "train", positions=positions,
                            enc_kv=enc_kv)
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
+    x = _norm(cfg, params["final_norm"], x)
     logits = _logits(cfg, params, x)
     return constrain(logits, ctx.rules, ("batch", "seq", "vocab")), aux
 
@@ -419,7 +433,7 @@ def lm_loss(cfg, params, tokens, labels, ctx: Ctx = Ctx(), media=None,
             params["cross"])
     x, _, aux = _run_stack(cfg, params, x, ctx, "train", positions=positions,
                            enc_kv=enc_kv)
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
+    x = _norm(cfg, params["final_norm"], x)
     w = params["embed"] if cfg.tied_embeddings else params["unembed"]
 
     def ce_chunk(h, y):
@@ -503,7 +517,7 @@ def prefill(cfg, params, tokens, cache_len, ctx: Ctx = Ctx(), media=None,
     x, caches, _ = _run_stack(cfg, params, x, ctx, "prefill",
                               positions=positions, cache_len=cache_len,
                               enc_kv=enc_kv)
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
+    x = _norm(cfg, params["final_norm"], x)
     logits = _logits(cfg, params, x[:, -1:, :])
     state = {"caches": caches, "pos": jnp.full((B,), S, jnp.int32)}
     if enc_kv is not None:
@@ -521,11 +535,12 @@ def decode_step(cfg, params, tokens, state, ctx: Ctx = Ctx()):
     if not cfg.use_rope:
         pe = jnp.take(params["pos_embed"], positions, axis=0)
         x = x + pe[:, None, :].astype(x.dtype)
-    x = constrain(x, ctx.rules, ("batch", "seq", "embed"))
+    x = constrain(_embed_norm(cfg, params, x), ctx.rules,
+                  ("batch", "seq", "embed"))
     x, caches, _ = _run_stack(cfg, params, x, ctx, "decode",
                               caches=state["caches"], positions=positions,
                               enc_kv=state.get("enc_kv"))
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
+    x = _norm(cfg, params["final_norm"], x)
     logits = _logits(cfg, params, x)
     logits = constrain(logits, ctx.rules, ("batch", "seq", "vocab"))
     new_state = dict(state)

@@ -131,3 +131,20 @@ def test_qwen2_decode_step_updates_cache_in_place(one_chip):
     assert ma.alias_size_in_bytes >= cache
     assert ma.temp_size_in_bytes < cache // 100, ma.temp_size_in_bytes
     assert compiled.as_text().count(" while(") == 1
+
+
+def test_rwkv6_full_width_prefill_loops_over_chunks(one_chip):
+    """rwkv6-3b's prefill of a 4096-token prompt at full width: two loops,
+    the layer scan and in it the chunk-to-chunk state recurrence (none
+    over tokens), and well under a GB of temporaries beside the weights."""
+    cfg = get_config("rwkv6-3b")
+    S = 4096
+    params = jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0),
+                                             jnp.bfloat16)))
+    tokens = _spec((1, S), jnp.int32, one_chip)
+    compiled = jax.jit(lambda p, t: M.prefill(cfg, p, t, S)).lower(
+        params, tokens).compile()
+    assert compiled.as_text().count(" while(") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
