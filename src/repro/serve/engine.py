@@ -24,6 +24,7 @@ from typing import Any, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import model as M
 from repro.serve.trace import Trace
@@ -65,6 +66,12 @@ class EngineIncomplete(RuntimeError):
 
 
 _OFF = contextlib.nullcontext()
+
+
+def _to_host(x) -> np.ndarray:
+    """The one device-to-host transfer of a decoding tick: the whole [B]
+    token vector."""
+    return np.asarray(x)
 
 
 class Engine:
@@ -184,17 +191,20 @@ class Engine:
                 logits, self.state = self._decode(self.params, self.cur_tok,
                                                   self.state)
                 next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # start the transfer now: it runs as soon as the argmax ends
+                next_tok.copy_to_host_async()
             self.last_logits = logits
             self.cur_tok = next_tok
-            # the first int() below would wait here too; waiting first
-            # keeps the wait for the device apart from the pulls
+            # the pull below would wait here too; waiting first keeps the
+            # wait for the device apart from the pull
             with self._span("device_wait"):
                 jax.block_until_ready(next_tok)
             with self._span("pull"):
-                self._count("host_syncs", len(active))
+                self._count("host_syncs")
                 self._count("decoded_tokens", len(active))
+                toks = _to_host(next_tok)
                 for s in active:
-                    tok = int(next_tok[s])
+                    tok = int(toks[s])
                     self.slot_out[s].append(tok)
                     self.slot_budget[s] -= 1
                     req = self.slot_req[s]

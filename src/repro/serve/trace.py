@@ -34,10 +34,12 @@ SPANS: Dict[str, str] = {
     "splice": "the eager copy of the request's cache into its slot",
     "first_token": "one host pull of the request's first token (two per "
                    "admission); the host waits here for prefill and splice",
-    "decode": "dispatch of serve_decode and of the argmax over its logits",
+    "decode": "dispatch of serve_decode, of the argmax over its logits "
+              "and of the tokens' copy to the host",
     "device_wait": "block_until_ready on the tick's tokens: the host waits "
                    "for the decode step on the device",
-    "pull": "one int() per live slot, and the slots' bookkeeping",
+    "pull": "one host pull of the tick's whole token vector, and the live "
+            "slots' bookkeeping on the host",
 }
 
 COUNTERS: Dict[str, str] = {
@@ -47,7 +49,7 @@ COUNTERS: Dict[str, str] = {
     "decoded_tokens": "tokens made by decode steps (one per live slot per "
                       "tick)",
     "host_syncs": "device-to-host transfers the engine forces: two per "
-                  "admission, one per live slot per tick",
+                  "admission, one per tick that decoded",
 }
 
 QUEUED = "queued"
