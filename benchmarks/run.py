@@ -1,14 +1,14 @@
-"""Benchmark harness: one entry per paper table/figure + roofline.
+"""Benchmark harness: one entry per paper table/figure and subsystem sweep.
 
 Prints ``name,us_per_call,derived`` CSV at the end (harness contract) and
 mirrors the rows into ``BENCH_sched.json`` so perf trajectory is machine-
 readable across PRs.
 
-  python -m benchmarks.run [--only exp1|exp2|exp3|sched|backfill|faults|roofline|sim_scale|telemetry]
+  python -m benchmarks.run [--only exp1|exp2|exp3|sched|backfill|preempt|faults|net_topo|sim_scale|telemetry|serve_fleet]
                            [--smoke]
 
-``--smoke`` runs a reduced sweep: jobs that support it (sched, sim_scale)
-shrink their fleet sizes; the full paper-scale experiment replays are
+``--smoke`` runs a reduced sweep of the jobs in ``SMOKE_JOBS``, each of
+which shrinks its fleet sizes; the full paper-scale experiment replays are
 skipped.
 """
 import argparse
@@ -24,7 +24,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced sweep (sched + sim_scale only)")
+                    help="reduced sweep (the SMOKE_JOBS only)")
     ap.add_argument("--json", default=None,
                     help="output path (default BENCH_sched.json, or "
                          "BENCH_sched_smoke.json under --smoke; not "
@@ -35,13 +35,13 @@ def main() -> None:
     csv_rows = []
     from benchmarks import (backfill, exp1_single_type, exp2_mixed,
                             exp3_frameworks, faults, net_topo, preempt,
-                            roofline, sched_efficiency, serve_fleet,
-                            sim_scale, telemetry)
+                            sched_efficiency, serve_fleet, sim_scale,
+                            telemetry)
     jobs = {"exp1": exp1_single_type.run, "exp2": exp2_mixed.run,
             "exp3": exp3_frameworks.run, "sched": sched_efficiency.run,
             "backfill": backfill.run, "preempt": preempt.run,
             "faults": faults.run, "net_topo": net_topo.run,
-            "roofline": roofline.run, "sim_scale": sim_scale.run,
+            "sim_scale": sim_scale.run,
             "telemetry": telemetry.run, "serve_fleet": serve_fleet.run}
     for name, fn in jobs.items():
         if args.only and args.only != name:

@@ -4,7 +4,7 @@
 
 Left: the paper's 4-node platform, 20 mixed MPI jobs, all six scenarios.
 Right: the same two-layer scheduler driving a 2-pod TPU fleet with
-arch-derived workloads (profiles from the dry-run roofline table).
+arch-derived workloads (profiles from each architecture's size and MoE).
 """
 import random
 

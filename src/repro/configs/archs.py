@@ -20,7 +20,6 @@ RECURRENTGEMMA_2B = register(ArchConfig(
     ffn_kind="geglu", norm_type="rms", tied_embeddings=True,
     embed_scale=True, final_softcap=30.0,
     rnn_width=2560, conv_width=4,
-    subquadratic=True,                       # RG-LRU + bounded local window
     source="arXiv:2402.19427; hf",
 ))
 
@@ -33,7 +32,6 @@ GEMMA3_1B = register(ArchConfig(
     local_window=512, rope_theta=1_000_000.0, rope_theta_local=10_000.0,
     qk_norm=True, ffn_kind="geglu", norm_type="rms",
     tied_embeddings=True, embed_scale=True,
-    subquadratic=True,                       # 5:1 local:global hybrid, 128k ctx
     source="hf:google/gemma-3-1b-pt; unverified",
 ))
 
@@ -73,7 +71,6 @@ RWKV6_3B = register(ArchConfig(
     norm_type="layer", norm_eps=1e-5, embed_norm=True,
     tied_embeddings=False,
     mix_lora_rank=32, decay_lora_rank=64, head_size_divisor=8,
-    subquadratic=True,                       # attention-free, O(1) state
     source="arXiv:2404.05892 (Eagle and Finch), Section 4; "
            "hf:RWKV/v6-Finch-3B-HF config.json; LoRA ranks from RWKV-LM "
            "RWKV_Tmix_x060",
@@ -117,7 +114,6 @@ WHISPER_SMALL = register(ArchConfig(
     use_rope=False, ffn_kind="gelu", norm_type="layer", tied_embeddings=True,
     encoder=EncoderSpec(n_layers=12, n_ctx=1500, d_model=768, n_heads=12,
                         d_ff=3072),
-    max_target_len=448,
     source="arXiv:2212.04356; unverified",
 ))
 

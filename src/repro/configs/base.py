@@ -92,14 +92,9 @@ class ArchConfig:
 
     # enc-dec (audio family)
     encoder: Optional[EncoderSpec] = None
-    max_target_len: int = 448        # whisper decoder architectural cap
 
     # vlm stub frontend
     n_media_tokens: int = 0          # precomputed patch embeddings prepended
-
-    # capability flags (drive shape applicability)
-    subquadratic: bool = False       # may run long_500k
-    is_decoder: bool = True
 
     source: str = ""                 # provenance tag from the assignment table
 
@@ -126,8 +121,8 @@ class ArchConfig:
         return ((self.vocab + m - 1) // m) * m
 
     def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks), used for 6·N·D
-        roofline maths and HBM napkin checks."""
+        """Analytic parameter count (embedding + blocks), used by the
+        planner's HBM napkin checks and heuristic profiles."""
         n = self.padded_vocab * self.d_model          # embed
         if not self.tied_embeddings:
             n += self.padded_vocab * self.d_model     # unembed
@@ -140,17 +135,6 @@ class ArchConfig:
                    + 2 * e.d_model * e.d_ff + 4 * e.d_model)
             n += e.n_layers * per + e.n_ctx * e.d_model
         return n
-
-    def active_param_count(self) -> int:
-        """Params touched per token (MoE: top_k of n_experts)."""
-        if self.moe is None:
-            return self.param_count()
-        full = self.param_count()
-        kinds = list(self.pattern_unit) * self.n_units + list(self.tail)
-        moe_blocks = sum(1 for k in kinds if k in ("attn", "local"))
-        per_expert = 3 * self.d_model * self.moe.d_ff_expert
-        dead = moe_blocks * (self.moe.n_experts - self.moe.top_k) * per_expert
-        return full - dead
 
     def _block_params(self, kind: str) -> int:
         D, H, K, hd, F = (self.d_model, self.n_heads, self.n_kv_heads,

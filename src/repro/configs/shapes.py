@@ -1,17 +1,13 @@
-"""The assigned input-shape set and (arch × shape) cell enumeration.
+"""The assigned input shapes, which ``core.meshplan.plan_job`` plans for.
 
-Shapes lower different entry points:
-  train_4k     -> train_step  (fwd + bwd + optimizer)
-  prefill_32k  -> prefill_step (fwd, writes KV cache)
-  decode_32k   -> serve_step  (1 new token against a seq_len KV cache)
-  long_500k    -> serve_step  (sub-quadratic archs only, per assignment)
+  train_4k     train step (fwd + bwd + optimizer)
+  prefill_32k  prefill (fwd, writes the KV cache)
+  decode_32k   decode (1 new token against a seq_len KV cache)
+  long_500k    decode at a 512k context
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, Optional, Tuple
-
-from repro.configs.base import ArchConfig, list_configs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,36 +24,3 @@ DECODE_32K = ShapeSpec("decode_32k", "decode", 32_768, 128)
 LONG_500K = ShapeSpec("long_500k", "decode", 524_288, 1)
 
 SHAPES = {s.name: s for s in (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)}
-
-
-def shape_skip_reason(cfg: ArchConfig, shape: ShapeSpec) -> Optional[str]:
-    """Return a human-readable skip reason, or None if the cell runs.
-
-    Per the assignment: long_500k needs sub-quadratic attention — run for
-    SSM/hybrid/linear-attention archs, skip (and document) for pure
-    full-attention archs.  Whisper's decoder context is architecturally capped
-    at max_target_len, far below 500k.
-    """
-    if shape.name == "long_500k":
-        if cfg.encoder is not None:
-            return ("enc-dec decoder context architecturally capped at "
-                    f"{cfg.max_target_len} tokens; 500k-decode undefined")
-        if not cfg.subquadratic:
-            return ("pure full-attention arch: 500k context requires "
-                    "sub-quadratic attention (assignment rule)")
-    if shape.kind == "decode" and not cfg.is_decoder:
-        return "encoder-only arch has no decode step"
-    return None
-
-
-def all_cells() -> Iterator[Tuple[ArchConfig, ShapeSpec, Optional[str]]]:
-    """All 40 (arch × shape) cells with their skip reason (None = runs)."""
-    for cfg in list_configs().values():
-        for shape in (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K):
-            yield cfg, shape, shape_skip_reason(cfg, shape)
-
-
-def runnable_cells() -> Iterator[Tuple[ArchConfig, ShapeSpec]]:
-    for cfg, shape, skip in all_cells():
-        if skip is None:
-            yield cfg, shape

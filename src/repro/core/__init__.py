@@ -2,7 +2,7 @@
 
 **Application layer** — decides *what to ask for* and *who goes first*:
 
-* ``planner`` (Algorithm 1) — granularity selection: the roofline-derived
+* ``planner`` (Algorithm 1) — granularity selection: the job's
   profile (network / CPU / memory, ``profiles``) picks how many workers,
   nodes and groups a submission should request;
 * ``controller`` (Algorithm 2) — the MPI-aware task->worker mapping,
@@ -178,7 +178,7 @@ from repro.core.policies import (POLICIES, ConservativeBackfillPolicy,
                                  PlacementPolicy, TaskGroupPolicy,
                                  make_policy)
 from repro.core.profiles import (MEM_WEIGHT, PAPER_BENCHMARKS, Profile,
-                                 Workload, classify_roofline)
+                                 Workload)
 from repro.core.queues import (QUEUES, FairShareQueue, FifoQueue,
                                PriorityQueue, QueueDiscipline, make_queue)
 from repro.core.scenarios import (SCENARIOS, TENANT_CLASSES, diurnal_poisson,
@@ -205,7 +205,7 @@ __all__ = ["Cluster", "Node", "fleet_cluster", "hetero_cluster",
            "PlacementPolicy", "DefaultPolicy", "TaskGroupPolicy",
            "EasyBackfillPolicy", "ConservativeBackfillPolicy",
            "make_policy", "MEM_WEIGHT", "PAPER_BENCHMARKS",
-           "Profile", "Workload", "classify_roofline", "QUEUES",
+           "Profile", "Workload", "QUEUES",
            "QueueDiscipline", "FifoQueue", "PriorityQueue",
            "FairShareQueue", "make_queue", "SCENARIOS", "TENANT_CLASSES",
            "diurnal_poisson", "diurnal_request_stream", "get_scenario",

@@ -44,7 +44,8 @@ class JobPlan:
 
 
 # HBM napkin model (v5e: 16 GiB/chip) used to pick param layouts before the
-# first compile; the dry-run's memory_analysis() is the ground truth.
+# first compile; the compiled program's own memory analysis is the ground
+# truth.
 HBM_PER_CHIP = 16 * 2 ** 30
 
 
@@ -57,8 +58,7 @@ def _param_bytes(cfg: ArchConfig, optimizer: str) -> int:
 
 
 def default_profile(cfg: ArchConfig, shape: ShapeSpec) -> Profile:
-    """Pre-compile heuristic profile; the roofline pass replaces it with the
-    measured classification (profiles.classify_roofline)."""
+    """Heuristic profile from the shape's kind and the model's size."""
     if shape.kind == "decode":
         return Profile.MEMORY                 # decode reads params+cache/token
     # training/prefill: small dense models on many chips are collective-bound

@@ -12,8 +12,8 @@ Faithful transcription of the paper's pseudocode:
         N_n = 1, N_w = N_w (user default), N_g = N_n
 
 The planner's inputs are the job metadata (N_t fixed by the user — the
-``mpirun -np`` count / number of model shards), the *profile* (derived from
-the roofline analysis in this framework, see ``profiles.py``), and the
+``mpirun -np`` count / number of model shards), the *profile* (see
+``profiles.py``), and the
 cluster size (the paper reads it from Prometheus; we read it from the
 Cluster object).
 

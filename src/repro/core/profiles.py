@@ -1,12 +1,15 @@
 """Workload profiles (Algorithm 1 input).
 
 The paper classifies MPI jobs as network / CPU / memory intensive (hand-
-classified from MPI profiling, its Fig. 3).  This framework *derives* the
-profile from the roofline terms of the compiled program (dominant term):
+classified from MPI profiling, its Fig. 3).  For an accelerator job the
+classes stand for the dominant roofline term of its step:
 
     collective-bound  <->  "network"  (keep the job coarse / inside one domain)
     compute-bound     <->  "CPU"      (fine granularity is free, exploit it)
     hbm-bound         <->  "memory"   (fine granularity + balance to spread bw)
+
+An architecture's class is set by a heuristic on its size and on whether
+it is an MoE (``meshplan.default_profile``, ``launch.schedule.fleet_jobs``).
 
 The paper's five calibration benchmarks are also encoded here so the cluster
 simulator can reproduce the paper's experiments 1:1.
@@ -31,20 +34,6 @@ class Profile(str, enum.Enum):
 # (``estimates``): a mixed job presses the memory controllers at half the
 # weight of a pure STREAM-class job
 MEM_WEIGHT: Dict[Profile, float] = {Profile.MEMORY: 1.0, Profile.MIXED: 0.5}
-
-
-def classify_roofline(compute_s: float, hbm_s: float,
-                      collective_s: float) -> Profile:
-    """Dominant roofline term -> paper profile."""
-    terms = {Profile.CPU: compute_s, Profile.MEMORY: hbm_s,
-             Profile.NETWORK: collective_s}
-    dom = max(terms, key=terms.get)
-    # near-tie between compute and memory = the paper's "cpu+memory" class
-    if dom in (Profile.CPU, Profile.MEMORY):
-        lo, hi = sorted([compute_s, hbm_s])
-        if hi > 0 and lo / hi > 0.75 and max(compute_s, hbm_s) >= collective_s:
-            return Profile.MIXED
-    return dom
 
 
 @dataclasses.dataclass(frozen=True)

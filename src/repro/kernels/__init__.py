@@ -2,7 +2,7 @@
 
 The paper itself is an infrastructure/scheduling contribution (no kernel of
 its own); these kernels are the perf-critical layers of the *workloads* the
-scheduler manages, exercised by the roofline/perf iterations:
+scheduler manages:
 
   flash_attention   train/prefill attention (causal + sliding-window + GQA)
   decode_attention  flash-decode against ring-buffered KV caches

@@ -1,8 +1,7 @@
 """Jitted dispatchers for the Pallas kernels.
 
 Each op rearranges model-layout tensors into kernel layout, invokes the
-kernel, and registers its *analytic* FLOP count with the roofline ledger
-(kernels are custom calls, invisible to HLO dot parsing).
+kernel, and returns its result in model layout.
 
 ``interpret`` is the caller's explicit choice: ``False`` (the default)
 compiles the kernel with Mosaic for a TPU; ``True`` runs it through the
@@ -84,22 +83,3 @@ def wkv6(r, k, v, w, u, s0, *, block_t=64, interpret=False):
                                  interpret=interpret)
     y = y.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
     return y, s_last.reshape(B, H, hd, hd)
-
-
-# analytic FLOP formulas for the roofline ledger (kernels are custom calls,
-# so HLO dot parsing cannot see them)
-def flash_attention_flops(B, S, T, H, hd, causal):
-    full = 4.0 * B * S * T * H * hd          # qk^T + pv
-    return full / 2 if causal else full
-
-
-def decode_attention_flops(B, C, H, hd):
-    return 4.0 * B * C * H * hd
-
-
-def rglru_flops(B, S, W):
-    return 8.0 * B * S * W                   # elementwise recurrence
-
-
-def wkv6_flops(B, S, H, hd):
-    return 4.0 * B * S * H * hd * hd

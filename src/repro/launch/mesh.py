@@ -1,29 +1,21 @@
-"""Production mesh + sharding assembly for the launch/dry-run layer."""
+"""Host mesh + sharding assembly for the launchers."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs import ArchConfig, ShapeSpec
 from repro.models import model as M
-from repro.models.sharding import Rules, spec as rules_spec
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from repro.models.sharding import Rules
 
 
 def make_host_mesh(devices):
     """("data", "model") mesh over one host's chips.  A host is inside one
-    ICI domain, so tensor parallelism takes up to 16 of its chips (the
-    production mesh's ``model`` width) and data parallelism the rest."""
+    ICI domain, so tensor parallelism takes up to 16 of its chips and data
+    parallelism the rest."""
     n = len(devices)
     model = min(n, 16)
     return jax.make_mesh((n // model, model), ("data", "model"),
@@ -148,43 +140,10 @@ def train_state_shardings(mesh, rules: Rules, cfg: ArchConfig,
     return out
 
 
-# --------------------------------------------------------------------------
-# input specs (ShapeDtypeStructs, no allocation) per arch x shape
-# --------------------------------------------------------------------------
-def input_specs(cfg: ArchConfig, shape: ShapeSpec,
-                dtype=jnp.bfloat16) -> Dict[str, Any]:
-    B, S = shape.global_batch, shape.seq_len
-    specs: Dict[str, Any] = {}
-    if shape.kind in ("train", "prefill"):
-        specs["tokens"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        if shape.kind == "train":
-            specs["labels"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        if cfg.n_media_tokens:
-            specs["media"] = jax.ShapeDtypeStruct(
-                (B, cfg.n_media_tokens, cfg.d_model), dtype)
-        if cfg.encoder is not None:
-            specs["frames"] = jax.ShapeDtypeStruct(
-                (B, cfg.encoder.n_ctx, cfg.encoder.d_model), dtype)
-    else:  # decode: one token against a seq_len cache
-        specs["tokens"] = jax.ShapeDtypeStruct((B,), jnp.int32)
-        specs["state"] = jax.eval_shape(
-            lambda: M.init_decode_state(
-                cfg, B, S, dtype,
-                enc_kv=_enc_kv_struct(cfg, B, dtype)))
-    return specs
-
-
-def _enc_kv_struct(cfg, B, dtype):
-    if cfg.encoder is None:
-        return None
-    e = cfg.encoder
-    s = jax.ShapeDtypeStruct(
-        (cfg.n_units, B, e.n_ctx, cfg.n_kv_heads, cfg.head_dim), dtype)
-    return (s, s)
-
-
 def input_shardings(cfg: ArchConfig, shape: ShapeSpec, mesh,
                     rules: Rules) -> Dict[str, Any]:
+    """Shardings of a train step's inputs: tokens and labels over batch
+    (and seq, where the rules split it), media and frames over batch."""
     rules = effective_rules(rules, mesh)
     batch_ax = rules.batch
     bsize = _axis_size(mesh, batch_ax)
@@ -194,28 +153,10 @@ def input_shardings(cfg: ArchConfig, shape: ShapeSpec, mesh,
     if seq_ax is not None and shape.seq_len % max(_axis_size(mesh, seq_ax),
                                                   1) != 0:
         seq_ax = None
-    out: Dict[str, Any] = {}
-    if shape.kind in ("train", "prefill"):
-        out["tokens"] = NamedSharding(mesh, P(batch_ax, seq_ax))
-        if shape.kind == "train":
-            out["labels"] = NamedSharding(mesh, P(batch_ax, seq_ax))
-        if cfg.n_media_tokens:
-            out["media"] = NamedSharding(mesh, P(batch_ax, None, None))
-        if cfg.encoder is not None:
-            out["frames"] = NamedSharding(mesh, P(batch_ax, None, None))
-    else:
-        out["tokens"] = NamedSharding(mesh, P(batch_ax))
-        axes = M.decode_state_axes(cfg)
-        state_struct = jax.eval_shape(
-            lambda: M.init_decode_state(
-                cfg, shape.global_batch, shape.seq_len, jnp.bfloat16,
-                enc_kv=_enc_kv_struct(cfg, shape.global_batch,
-                                      jnp.bfloat16)))
-        brules = rules if batch_ax is not None else \
-            dataclasses.replace(rules, batch=None)
-        # a cache row holds a position's K heads side by side: it is split
-        # in whole heads or not at all
-        if cfg.n_kv_heads % _axis_size(mesh, brules.kv_heads):
-            brules = dataclasses.replace(brules, kv_heads=None)
-        out["state"] = tree_shardings(mesh, brules, state_struct, axes)
+    out: Dict[str, Any] = {"tokens": NamedSharding(mesh, P(batch_ax, seq_ax)),
+                           "labels": NamedSharding(mesh, P(batch_ax, seq_ax))}
+    if cfg.n_media_tokens:
+        out["media"] = NamedSharding(mesh, P(batch_ax, None, None))
+    if cfg.encoder is not None:
+        out["frames"] = NamedSharding(mesh, P(batch_ax, None, None))
     return out

@@ -16,8 +16,8 @@ from repro.core.simulator import Simulator
 
 
 def fleet_jobs(n_jobs: int, seed: int = 0):
-    """Arch-derived workloads for fleet mode: profiles from the dry-run
-    roofline classification (see benchmarks/roofline.py)."""
+    """Arch-derived workloads for fleet mode: profiles from a heuristic on
+    each architecture's size and whether it is an MoE."""
     from repro.configs import list_configs
     rng = random.Random(seed)
     mix = []

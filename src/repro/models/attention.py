@@ -1,14 +1,13 @@
 """GQA attention: full/causal/sliding-window, training + prefill + decode.
 
-Three implementations of the score/softmax/value core:
+Two implementations of the score/softmax/value core, chosen by
+``Ctx.attn_impl``:
 
-* ``xla_rect``  — q-block-chunked attention in plain jnp (lax.scan over query
-  blocks, full kv per block with masking).  Paper-faithful baseline path; for
-  causal masks it executes the full rectangle (2x flops waste — visible in
-  the roofline, driven down by the banded/pallas paths in §Perf).
-* ``xla_flash`` — banded pair-list flash (see ``xla_flash.py``): true causal /
-  local block skipping, online softmax, f32 accumulators.
-* ``pallas``    — Pallas TPU kernel (``repro.kernels``), same block structure.
+* ``xla_rect`` — q-block-chunked attention in plain jnp (lax.scan over query
+  blocks, full kv per block with masking).  The served path; for causal
+  masks it computes the full rectangle of scores.
+* ``pallas``   — the Pallas TPU flash kernel (``repro.kernels``): causal and
+  local blocks skipped, online softmax, f32 accumulators.
 
 KV cache: ring buffer of length ``min(max_len, window)`` for local layers —
 this is what makes gemma3/recurrentgemma long-context decode sub-quadratic.
@@ -17,13 +16,10 @@ ring wrap needs no special cases.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
-from repro.models import xla_flash
 from repro.models.sharding import constrain
 
 
@@ -164,17 +160,16 @@ def full_attention(params, x, *, cfg, kind, rules, impl="xla_rect",
     q = constrain(q, rules, ("batch", "seq", "heads", None))
     k = constrain(k, rules, ("batch", "seq", "kv_heads", None))
     v = constrain(v, rules, ("batch", "seq", "kv_heads", None))
-    if impl == "xla_flash":
-        ctx = xla_flash.flash_attention(q, k, v, positions[0], kvp,
-                                        causal=causal, window=window,
-                                        softcap=sc)
-    elif impl == "pallas":
+    if impl == "pallas":
         from repro.kernels import ops as kops
         ctx = kops.flash_attention(q, k, v, causal=causal, window=window,
                                    softcap=sc, interpret=interpret)
-    else:
+    elif impl == "xla_rect":
         ctx = _rect_attention(q, k, v, positions[0], kvp, causal=causal,
                               window=window, softcap=sc)
+    else:
+        raise ValueError(f"unknown attn_impl {impl!r}: "
+                         "expected 'xla_rect' or 'pallas'")
     y = _out_proj(params, ctx, rules)
     return y, (k, v)
 

@@ -37,7 +37,7 @@ class Ctx:
     """Execution context: sharding rules + implementation selection."""
     rules: Optional[Rules] = None
     mesh: Any = None
-    attn_impl: str = "xla_rect"      # xla_rect | xla_flash | pallas
+    attn_impl: str = "xla_rect"      # xla_rect | pallas
     rnn_impl: str = "xla"            # xla | pallas
     moe_impl: str = "dense"          # dense | ep | ep_a2a
     interpret: bool = False          # Pallas kernels through the interpreter

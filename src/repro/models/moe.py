@@ -156,25 +156,10 @@ def apply_dense(params, x, cfg):
 # --------------------------------------------------------------------------
 # shard_map expert parallelism
 # --------------------------------------------------------------------------
-# module-level switch (set by the launch layer / perf variants): quantize
-# expert weights to int8 for the ZeRO-3 gather (per-[expert, out-channel]
-# scales), halving gather bytes; the bf16 master copy is untouched.
-GATHER_QUANT = False
-
-
 def _hier_gather(w, fsdp_axes, axis):
     """ZeRO-3 just-in-time weight gather, one hop per mesh axis so the
     fast-domain (ICI) part never pays DCN rates — the paper's 'keep traffic
     in the smallest domain' rule applied to parameter gathers."""
-    if GATHER_QUANT:
-        scale = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=axis,
-                        keepdims=True) / 127.0
-        scale = jnp.maximum(scale, 1e-12)
-        q = jnp.clip(jnp.round(w.astype(jnp.float32) / scale),
-                     -127, 127).astype(jnp.int8)
-        for a in reversed(fsdp_axes):
-            q = jax.lax.all_gather(q, a, axis=axis, tiled=True)
-        return (q.astype(jnp.float32) * scale).astype(w.dtype)
     for a in reversed(fsdp_axes):
         w = jax.lax.all_gather(w, a, axis=axis, tiled=True)
     return w

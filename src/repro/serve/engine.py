@@ -7,9 +7,9 @@ for all active slots in lock-step — one ``decode_step`` per engine tick, the
 standard continuous-batching serving loop (prefill-on-admit, iteration-level
 scheduling).
 
-This is the substrate the decode_32k / long_500k dry-run cells lower
-(``serve_step`` = one engine tick), and what ``examples/serve_batch.py``
-drives end-to-end on CPU with a reduced config.
+The benchmark's serve cells (``bench/runners/serve.py``) drive it on the
+chip at full width, and ``examples/serve_batch.py`` drives it end-to-end on
+CPU with a reduced config.
 
 An engine given a ``repro.serve.trace.Trace`` records its steps as spans
 and counts its work and its host syncs (``serve/trace.py`` lists each);

@@ -1,9 +1,11 @@
-"""Deliverable (f): per-architecture smoke tests.
+"""Per-architecture smoke tests.
 
 Each assigned architecture is instantiated at a REDUCED config of the same
 family and run through one forward/train step + prefill + decode on CPU,
-asserting output shapes and finiteness.  The FULL configs are exercised only
-by the dry-run (ShapeDtypeStruct, no allocation).
+asserting output shapes and finiteness; its logical-axes trees are checked
+against the trees they describe.  Full widths are compiled for a TPU v5e in
+``tests/test_chip_compile.py`` and run on the chip by ``chip_smoke.py`` and
+the benchmark's cells.
 """
 import jax
 import jax.numpy as jnp
@@ -81,15 +83,67 @@ def test_prefill_decode_consistent_with_forward(arch):
 @pytest.mark.parametrize("arch", ["gemma3-1b", "llama3.2-1b",
                                   "recurrentgemma-2b"])
 def test_flash_impl_parity(arch):
+    """The Pallas flash kernel through the model (GQA, local windows,
+    softcap) against the served xla_rect core."""
     cfg = scaled_down(list_configs()[arch])
     key = jax.random.PRNGKey(2)
     params = M.init_params(cfg, key, jnp.float32, max_seq=64)
     tokens, _, kwargs = _inputs(cfg, key)
     lr, _ = jax.jit(lambda p: M.forward(
         cfg, p, tokens, M.Ctx(attn_impl="xla_rect"), **kwargs))(params)
+    ctx = M.Ctx(attn_impl="pallas", interpret=True)
     lf, _ = jax.jit(lambda p: M.forward(
-        cfg, p, tokens, M.Ctx(attn_impl="xla_flash"), **kwargs))(params)
-    assert jnp.max(jnp.abs(lr - lf)) < 2e-4
+        cfg, p, tokens, ctx, **kwargs))(params)
+    assert jnp.max(jnp.abs(lr - lf)) < 2e-4, \
+        float(jnp.max(jnp.abs(lr - lf)))
+
+
+def test_unknown_attn_impl_raises():
+    cfg = scaled_down(list_configs()["llama3.2-1b"])
+    params = jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.float32, max_seq=64))
+    tokens = jnp.zeros((B, S), jnp.int32)
+    with pytest.raises(ValueError, match="no_such_impl"):
+        jax.eval_shape(lambda p: M.forward(
+            cfg, p, tokens, M.Ctx(attn_impl="no_such_impl")), params)
+
+
+def _is_axes(x):
+    """A leaf of an axes tree: one name (or None) per dimension."""
+    return isinstance(x, tuple) and all(a is None or isinstance(a, str)
+                                        for a in x)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_axes_mirror_trees(arch):
+    """``param_axes`` and ``decode_state_axes`` mirror the trees they name
+    axes for, leaf by leaf; the engine finds each cache's batch axis there."""
+    cfg = scaled_down(list_configs()[arch])
+    params = jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.PRNGKey(0), jnp.float32, max_seq=64))
+    axes = M.param_axes(cfg)
+    assert (jax.tree.structure(axes, is_leaf=_is_axes)
+            == jax.tree.structure(params))
+    for leaf, ax in zip(jax.tree.leaves(params),
+                        jax.tree.leaves(axes, is_leaf=_is_axes)):
+        assert len(ax) == leaf.ndim, (arch, ax, leaf.shape)
+
+    batch, cache_len = 3, 16            # 3: no other dim of the caches
+    enc_kv = None
+    if cfg.encoder is not None:
+        kv = jax.ShapeDtypeStruct(
+            (cfg.n_units, batch, cfg.encoder.n_ctx, cfg.n_kv_heads,
+             cfg.head_dim), jnp.float32)
+        enc_kv = (kv, kv)
+    state = jax.eval_shape(lambda: M.init_decode_state(
+        cfg, batch, cache_len, jnp.float32, enc_kv=enc_kv))
+    saxes = M.decode_state_axes(cfg)
+    assert (jax.tree.structure(saxes, is_leaf=_is_axes)
+            == jax.tree.structure(state))
+    for leaf, ax in zip(jax.tree.leaves(state),
+                        jax.tree.leaves(saxes, is_leaf=_is_axes)):
+        assert len(ax) == leaf.ndim, (arch, ax, leaf.shape)
+        assert leaf.shape[ax.index("batch")] == batch, (arch, ax, leaf.shape)
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-2b", "rwkv6-3b"])
